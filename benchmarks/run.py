@@ -44,6 +44,8 @@ def all_benchmarks():
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark keys")

@@ -29,6 +29,7 @@ import argparse
 import asyncio
 import json
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import PAPER_RAMDISK, grid
 from repro.core.trace import GenSpec, generate_workflow, to_workflow
 from repro.serve import AdvisorRequest, AdvisorServer, DeadlineExceeded
@@ -131,6 +132,7 @@ async def _selftest(port: int) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=7081)

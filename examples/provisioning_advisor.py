@@ -42,6 +42,7 @@ Passing `--devices`/`--workers` alone implies the matching backend.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (MB, PAPER_RAMDISK, MultiprocBackend, ShardedBackend,
                         SweepSession, explore, explore_many, grid,
                         pareto_front, parse_faults)
@@ -146,6 +147,7 @@ def family_sweep(wfs, cands, st, session):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=20)
     ap.add_argument("--queries", type=int, default=100)

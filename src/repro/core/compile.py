@@ -45,6 +45,9 @@ from .types import (CTRL_BYTES, FileAttr, Placement, StorageConfig, Task,
 
 MAXD = 4
 
+# smallest normal f64; task runtimes below it compile as zero
+_TINY = float(np.finfo(np.float64).tiny)
+
 # process-wide count of compile_workflow executions; ground truth for the
 # compile-cache counters (benchmarks/tests assert a warm sweep leaves it flat)
 _N_COMPILES = 0
@@ -322,7 +325,11 @@ def compile_workflow(wf: Workflow, cfg: StorageConfig, *,
         ready = b.barrier(read_ends) if read_ends else start
 
         # --- compute -----------------------------------------------------------
-        comp = b.op(b.r_cpu(chost), CLS_CPU, [ready], extra=t.runtime)
+        # XLA flushes subnormal floats to zero, so a runtime below the
+        # smallest normal f64 is zero here too: the host reference and
+        # the device then simulate the same op.
+        runtime = t.runtime if abs(t.runtime) >= _TINY else 0.0
+        comp = b.op(b.r_cpu(chost), CLS_CPU, [ready], extra=runtime)
 
         # --- writes -------------------------------------------------------------
         write_ends = []

@@ -71,11 +71,12 @@ from . import shard as _shard
 CacheKey = Tuple[int, int, int, bool, int, bool, bool]
 
 # the engine's ``sim_engine`` knob: what the scan-mode executable body is
-# built on. "auto" takes the Pallas kernel wherever it can run (interpret
-# mode on CPU, Mosaic on TPU) and falls back to XLA otherwise (counted in
-# `CacheStats.kernel_fallbacks`); "pallas" insists (raising where
-# unsupported); "xla" keeps the plain lax.scan body. Exact mode always
-# runs the XLA while_loop — the kernel is scan-only.
+# built on. "auto" takes the Pallas kernel where the platform runs it
+# (CPU, interpret mode) and the XLA lax.scan body elsewhere — on TPU by
+# design, since Mosaic refuses the kernel (`sweep_scan.ops.TPU_REFUSAL`);
+# "pallas" insists (raising where the kernel cannot run); "xla" keeps the
+# plain lax.scan body. Exact mode always runs the XLA while_loop — the
+# kernel is scan-only.
 SIM_ENGINES = ("auto", "pallas", "xla")
 
 # a sharded bucket must carry at least this many real op-rows
@@ -117,7 +118,8 @@ class CacheStats:
                                   # kernel (scan mode, sim_engine auto/pallas)
     kernel_fallbacks: int = 0     # scan batches that wanted the kernel
                                   # (sim_engine="auto") but fell back to XLA
-                                  # because Pallas can't run here
+                                  # because it cannot run here; the TPU's XLA
+                                  # body is its platform's path, not counted
     worker_rows: Dict[str, int] = field(default_factory=dict)
                                   # rows simulated per worker process (padded) —
                                   # the multiproc sibling of device_rows
@@ -189,13 +191,13 @@ class SweepEngine:
     tunes the adaptive placement threshold (0 = always shard).
 
     ``sim_engine`` picks the scan-mode executable body (`SIM_ENGINES`):
-    "auto" builds on the fused Pallas `kernels.sweep_scan` kernel
-    wherever Pallas can run (interpret mode on CPU, Mosaic on TPU) and
-    falls back to the XLA lax.scan body otherwise
-    (``stats.kernel_fallbacks`` counts that); "pallas" insists; "xla"
+    "auto" builds on the fused Pallas `kernels.sweep_scan` kernel where
+    the platform runs it (CPU, interpret mode) and on the XLA lax.scan
+    body elsewhere — TPU included, whose compiler refuses the kernel;
+    "pallas" insists, and raises with the compiler's reason on TPU; "xla"
     opts out. The two bodies are element-wise identical
-    (tests/test_sweep_kernel.py), so the knob is purely a throughput
-    decision — exact mode always runs the XLA while_loop.
+    (tests/test_sweep_kernel.py) — exact mode always runs the XLA
+    while_loop.
 
     ``workers`` is the engine's default host-process fan-out: the search
     layer (`explore`/`explore_many`/`successive_halving`) and
@@ -277,16 +279,19 @@ class SweepEngine:
     def _use_kernel(self, exact: bool) -> bool:
         """Resolve the ``sim_engine`` knob for one scan batch — at
         trace time, before the executable is built, so an unsupported
-        backend never traces a Pallas call it cannot run."""
+        backend never traces a Pallas call it cannot run. The refusal
+        says whether its XLA body counts as a fallback."""
         if exact or self.sim_engine == "xla":
             return False
-        if sweep_scan_ops.pallas_supported():
+        refusal = sweep_scan_ops.kernel_refusal()
+        if refusal is None:
             return True
         if self.sim_engine == "pallas":
             raise RuntimeError(
-                "sim_engine='pallas' but Pallas cannot run on backend "
-                f"{jax.default_backend()!r}; use 'auto' to fall back")
-        self.stats.kernel_fallbacks += 1
+                "sim_engine='pallas' but the Pallas kernel cannot run on "
+                f"backend {jax.default_backend()!r}: {refusal.reason}")
+        if refusal.fallback:
+            self.stats.kernel_fallbacks += 1
         return False
 
     # -- executable cache ------------------------------------------------------

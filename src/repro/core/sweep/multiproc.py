@@ -49,6 +49,7 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from ...obs.trace import NULL_TRACER, Tracer, WireSpan
@@ -149,6 +150,11 @@ _WORKER_XLA_FLAGS = ("--xla_cpu_multi_thread_eigen=false "
 
 
 def _worker_init() -> None:
+    # workers are host workers by design: on an accelerator host the
+    # parent holds the chip, and a worker that reached for it would fail
+    # or hang. Pinned before the worker's first computation, while no
+    # backend has been initialized yet.
+    jax.config.update("jax_platforms", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "intra_op_parallelism_threads" not in flags:
         os.environ["XLA_FLAGS"] = f"{flags} {_WORKER_XLA_FLAGS}".strip()

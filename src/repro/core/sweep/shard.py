@@ -35,11 +35,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # JAX >= 0.7 promotes shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 from ...launch.mesh import make_candidates_mesh
 from .buckets import bucket_pow2
 
@@ -118,15 +113,9 @@ def sharded_executable(vmapped_fn, mesh: Mesh, n_args: int = 2):
     axis = mesh.axis_names[0]
     spec = PartitionSpec(axis)
     specs = (spec,) * n_args
-    # replication checking has no rule for lax.while_loop (the exact-mode
-    # body) on older JAX; it is safe to skip — every output is fully
-    # partitioned, nothing is claimed replicated. The kwarg was renamed
-    # check_rep -> check_vma around JAX 0.7.
-    try:
-        mapped = shard_map(vmapped_fn, mesh=mesh, in_specs=specs,
-                           out_specs=spec, check_rep=False)
-    except TypeError:
-        mapped = shard_map(vmapped_fn, mesh=mesh, in_specs=specs,
+    # skipping the varying-manual-axes check is safe: every output is
+    # fully partitioned, nothing is claimed replicated
+    mapped = jax.shard_map(vmapped_fn, mesh=mesh, in_specs=specs,
                            out_specs=spec, check_vma=False)
     return jax.jit(mapped)
 

@@ -1,17 +1,15 @@
-"""x64 compatibility shim.
+"""Scoped 64-bit mode for the simulators.
 
 The simulators run in 64-bit mode (times in seconds need more than f32's
 7 digits to reproduce the oracle's FIFO tie-breaking), scoped to a
-context manager so the rest of the framework stays in f32/bf16. The
-context-manager API has moved between JAX releases — ``jax.enable_x64``
-on some versions, ``jax.experimental.enable_x64`` on others — so every
-call site goes through this wrapper instead of touching jax directly.
+context manager so the rest of the framework stays in f32/bf16. XLA
+compiles the f64 scan and exact bodies for TPU v5e too, where f64
+arithmetic is emulated in software.
 
-``REPRO_SIM_X64=0`` keeps the shim from switching into 64-bit mode at
-all — the whole simulation stack then runs in default f32, the only
-option on accelerators without f64 support (TPU). Scan-mode FIFO
-tie-breaking loses its bit-faithfulness guarantee in f32, but scan must
-still agree with exact mode within the golden fixture tolerance
+``REPRO_SIM_X64=0`` keeps the simulators out of 64-bit mode — the whole
+simulation stack then runs in default f32. Scan-mode FIFO tie-breaking
+loses its bit-faithfulness guarantee in f32, but scan must still agree
+with exact mode within the golden fixture tolerance
 (`tests/test_sweep_kernel.py::test_sweep_f32_within_golden_rtol` pins
 this; every array-construction site pins its dtype via canonicalization
 rather than f64 literals, so no row of a batch silently disagrees with
@@ -23,21 +21,16 @@ import os
 
 import jax
 
-try:
-    _enable_x64 = jax.enable_x64
-except AttributeError:  # current JAX: context manager lives in experimental
-    from jax.experimental import enable_x64 as _enable_x64
-
 
 def x64_wanted() -> bool:
     """False when the operator pinned the simulators to f32
-    (``REPRO_SIM_X64=0`` — f32-only accelerators). Read per call, so
-    tests can flip it without reloading modules."""
+    (``REPRO_SIM_X64=0``). Read per call, so tests can flip it without
+    reloading modules."""
     return os.environ.get("REPRO_SIM_X64", "1") != "0"
 
 
 def enable_x64(enabled: bool = True):
-    """Context manager switching JAX into 64-bit mode (on any JAX).
-    With ``REPRO_SIM_X64=0`` the context is a no-op that *keeps* the
-    default f32 world instead."""
-    return _enable_x64(enabled and x64_wanted())
+    """Context manager switching JAX into 64-bit mode. With
+    ``REPRO_SIM_X64=0`` the context is a no-op that *keeps* the default
+    f32 world instead."""
+    return jax.enable_x64(enabled and x64_wanted())

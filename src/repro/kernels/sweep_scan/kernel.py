@@ -19,10 +19,9 @@ with no per-step loop-carried tuple shuffling. Every arithmetic step
 so results are bit-identical, not approximately equal
 (tests/test_sweep_kernel.py asserts element-wise equality).
 
-On CPU hosts the kernel runs in interpret mode (all five CI legs
-exercise it); on TPU it compiles to Mosaic. f64 rides interpret mode on
-CPU — the x64 sweep path — while a TPU build would run the f32 sweep
-(`REPRO_SIM_X64=0`, see `repro.core.x64`).
+The kernel runs only in interpret mode on CPU, where the tests hold it
+bit-identical to the reference. Mosaic refuses it for TPU (see
+`ops.TPU_REFUSAL`), so the engine builds TPU scan executables on XLA.
 """
 from __future__ import annotations
 
@@ -79,12 +78,11 @@ def _kernel(res_ref, dur_ref, lag_ref, deps_ref, mk_ref, end_ref,
 
 def sweep_scan_kernel(res: jax.Array, dur: jax.Array, lag: jax.Array,
                       deps: jax.Array, *, n_resources: int,
-                      block_rows: int = BLOCK_ROWS,
-                      interpret: bool = False
+                      block_rows: int = BLOCK_ROWS
                       ) -> tuple[jax.Array, jax.Array]:
     """res i32[C, N], dur/lag f[C, N], deps i32[C, N, MAXD] ->
-    (makespan f[C], end f[C, N]). N must divide by the effective block
-    (always true for the engine's pow2 buckets)."""
+    (makespan f[C], end f[C, N]), in interpret mode. N must divide by
+    the effective block (always true for the engine's pow2 buckets)."""
     C, N = res.shape
     maxd = deps.shape[-1]
     block = min(block_rows, N)
@@ -115,5 +113,5 @@ def sweep_scan_kernel(res: jax.Array, dur: jax.Array, lag: jax.Array,
             pltpu.VMEM((N,), dur.dtype),             # completion times
             pltpu.VMEM((1,), dur.dtype),             # running makespan
         ],
-        interpret=interpret,
+        interpret=True,
     )(res, dur, lag, deps)

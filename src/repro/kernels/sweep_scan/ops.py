@@ -3,33 +3,55 @@ and the pure-XLA reference.
 
 `sweep_scan` is what `SweepEngine` builds its scan-mode executables on
 (behind the ``sim_engine`` knob). Dispatch happens at trace time —
-`pallas_supported()` is an ordinary Python predicate evaluated while the
-executable is being built, so an unsupported backend (or a JAX without
-Pallas) traces the reference path instead of failing at run time. The
-engine counts which way the dispatch went (`CacheStats.kernel_buckets` /
-``kernel_fallbacks``), so the fallback is observable, not silent.
+`kernel_refusal()` is an ordinary Python predicate evaluated while the
+executable is being built, so a backend the kernel cannot run on traces
+the reference path instead of failing at run time.
 
-On CPU the kernel runs in interpret mode — a correctness harness, not a
-speedup (every CI leg runs it); compiled Mosaic execution needs a TPU.
+Where the kernel runs is decided here, from the platform, and nowhere
+else (`kernel_refusal`): on CPU it runs in interpret mode, a correctness
+harness for the tests and not a speedup. On TPU the chip's compiler
+refuses it, so the TPU scan body is XLA's ``lax.scan``.
 """
 from __future__ import annotations
+
+from typing import NamedTuple, Optional
 
 import jax
 
 from .kernel import BLOCK_ROWS, sweep_scan_kernel
 from .ref import sweep_scan_ref
 
+# Why Mosaic (the TPU Pallas compiler) refuses `kernel.sweep_scan_kernel`,
+# as compiled ahead of time for v5e in f32 and f64: the ``(1, block)``
+# BlockSpecs over ``(C, N)`` break the (8, 128) tiling rule ("last two
+# dimensions of your block shape are divisible by 8 and 128"), and at
+# C=1 the scalar scratch stores fail with "Cannot store scalars to VMEM".
+# The kernel is a scalar loop over an N-sized completion table read at
+# random; making it lower is a redesign, not a fix.
+TPU_REFUSAL = ("Mosaic refuses the sweep_scan kernel on TPU: its (1, block) "
+               "BlockSpecs over (C, N) break the (8, 128) tiling rule, and "
+               "its scalar scratch stores cannot be placed in VMEM")
 
-def pallas_supported() -> bool:
-    """Can `sweep_scan` take the Pallas path on the current backend?
-    CPU qualifies via interpret mode; TPU compiles to Mosaic. Evaluated
-    at trace time by the engine's executable builder."""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except ImportError:
-        return False
-    return jax.default_backend() in ("cpu", "tpu")
+
+class Refusal(NamedTuple):
+    """Why the kernel cannot run on a backend. ``fallback`` says whether
+    the XLA body taken instead counts as a fallback: not on TPU, where
+    XLA is the platform's own scan body by design."""
+    reason: str
+    fallback: bool
+
+
+def kernel_refusal() -> Optional[Refusal]:
+    """Why the kernel cannot run on the current backend, or None where
+    it can (CPU, in interpret mode). Evaluated at trace time by the
+    engine's executable builder."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return None
+    if platform == "tpu":
+        return Refusal(TPU_REFUSAL, fallback=False)
+    return Refusal(f"the kernel has no Pallas lowering for backend "
+                   f"{platform!r}", fallback=True)
 
 
 def sweep_scan(res: jax.Array, dur: jax.Array, lag: jax.Array,
@@ -40,11 +62,10 @@ def sweep_scan(res: jax.Array, dur: jax.Array, lag: jax.Array,
     deps i32[C, N, MAXD] -> (makespan f[C], end f[C, N]).
 
     ``use_kernel`` is decided by the caller (the engine resolves its
-    ``sim_engine`` knob against `pallas_supported`); both paths are
+    ``sim_engine`` knob against `kernel_refusal`); both paths are
     element-wise identical.
     """
     if not use_kernel:
         return sweep_scan_ref(res, dur, lag, deps, n_resources=n_resources)
     return sweep_scan_kernel(res, dur, lag, deps, n_resources=n_resources,
-                             block_rows=block_rows,
-                             interpret=jax.default_backend() != "tpu")
+                             block_rows=block_rows)

@@ -12,19 +12,7 @@ flops (`tests/test_dryrun_smoke.py`).
 """
 from __future__ import annotations
 
-from typing import Dict
-
 from repro.models.config import ArchConfig, ShapeConfig
-
-
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """`compiled.cost_analysis()` normalized across JAX versions: older
-    releases return a one-element list of dicts, newer ones the dict
-    itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
 
 
 def _attn_flops(arch: ArchConfig, B: int, Sq: int, Skv: int, *,

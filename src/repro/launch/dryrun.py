@@ -26,8 +26,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs as cfgs
-from repro.launch.analytic import cost_analysis_dict
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import (ArchConfig, ShapeConfig, abstract, decode_step,
                           init_decode_state, loss_fn, model_defs, n_params)
 from repro.models.layers import abstract_params, is_def
@@ -124,7 +123,7 @@ def lower_cell(arch: ArchConfig, shape: ShapeConfig, mesh, *,
                use_kernel: bool = False, unroll: bool = False):
     """Build + lower + compile one cell. Returns (compiled, lowered)."""
     pspecs = param_specs(arch, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = adamw.AdamWConfig()
             # microbatching: pick the per-device microbatch so the remat'd
@@ -211,7 +210,7 @@ def delta_costs(arch: ArchConfig, shape: ShapeConfig, mesh, *,
         compiled, _ = lower_cell(red, shape, mesh, use_kernel=use_kernel,
                                  unroll=True)
         txt = compiled.as_text()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         out[tag] = {"units": units,
                     "coll": collective_bytes(txt)["total"],
                     "coll_by_kind": collective_bytes(txt),

@@ -11,27 +11,11 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-
-try:  # JAX >= 0.6: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older JAX: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    `jax.set_mesh` where available; on older JAX the Mesh object itself is
-    the context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

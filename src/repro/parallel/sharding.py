@@ -167,19 +167,9 @@ SEQ_SHARD_ACTIVATIONS = False   # §Perf L6: measured 4x collective regression
 
 
 def current_mesh():
-    """The ambient mesh, or None. `jax.sharding.get_abstract_mesh()` on
-    current JAX; the thread-local physical mesh (set by `with mesh:`) on
-    older releases."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    try:
-        if get_abstract is not None:
-            mesh = get_abstract()
-        else:
-            from jax._src import mesh as mesh_lib
-            mesh = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", True) or not mesh.axis_names:
+    """The ambient mesh (set by `jax.set_mesh`), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return None
     return mesh
 

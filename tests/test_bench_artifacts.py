@@ -114,6 +114,9 @@ def test_status_flows_into_json_artifact(tmp_path, monkeypatch, capsys):
             Row("toy/errored", -2.0, "ERROR: boom", status="error")]
     monkeypatch.setattr(bench_run, "all_benchmarks",
                         lambda: {"toy": lambda: rows})
+    # with the variable set, main's compile-cache helper leaves this
+    # process's JAX config alone (no cache leaks into later tests)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     out = tmp_path / "bench.json"
     assert bench_run.main(["--only", "toy", "--json", str(out)]) == 0
     capsys.readouterr()

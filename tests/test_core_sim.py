@@ -211,3 +211,14 @@ else:
         rng = np.random.default_rng(1000 + seed)
         wf, cfg = make_random_workflow(rng)
         check_slower_network_never_faster(wf, cfg, float(rng.uniform(1.5, 4.0)))
+
+
+@pytest.mark.parametrize("runtime", [5e-324, 1e-310, 0.0, 1e-300])
+def test_subnormal_runtime_matches_oracle(runtime):
+    """XLA flushes subnormal floats to zero; the compiler does the same
+    to task runtimes, so exact mode still equals the host reference."""
+    wf = Workflow(tasks=[Task(tid=0, inputs=(), outputs=(("f0", 0),),
+                              runtime=runtime)], name="subnormal")
+    cfg = collocated_config(3, chunk_size=128 * 1024, replication=1,
+                            placement=Placement.ROUND_ROBIN)
+    check_exact_equals_oracle(wf, cfg)

@@ -48,7 +48,7 @@ def test_analytic_flops_match_hlo_on_small_dense():
     f = jax.jit(lambda p, t: forward(p, t, cfg, remat=False, unroll=True))
     toks = jnp.zeros((2, 64), jnp.int32)
     comp = f.lower(params, toks).compile()
-    hlo_flops = float(analytic.cost_analysis_dict(comp).get("flops", 0.0))
+    hlo_flops = float(comp.cost_analysis().get("flops", 0.0))
     ours = analytic.forward_flops(cfg, 2, 64)
     # bf16 promotion/fusions make exact equality impossible; within 2x and
     # same order of magnitude is the guard we need for roofline sanity

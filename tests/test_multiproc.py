@@ -431,3 +431,19 @@ def test_late_result_after_failed_cancel_is_counted():
     assert eng.stats.mp_late_drops == eng.stats.mp_items > 0
     assert eng.stats.mp_fallbacks == eng.stats.mp_items
     assert handle.respawns == 0
+
+
+def test_spawned_worker_is_pinned_to_cpu(monkeypatch):
+    """Workers are host workers: on an accelerator host the parent holds
+    the chip, so a worker must never reach for it. The spawned child
+    inherits a platform it cannot use; `_worker_init` must pin the CPU
+    before the worker's first computation."""
+    import jax
+    monkeypatch.setenv("JAX_PLATFORMS", "no-such-platform")
+    handle = multiproc.PoolHandle(1)
+    try:
+        platform = handle.executor().submit(jax.default_backend) \
+            .result(timeout=120)
+    finally:
+        handle.close()
+    assert platform == "cpu"

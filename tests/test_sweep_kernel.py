@@ -42,7 +42,8 @@ from repro.core.sweep.engine import SIM_ENGINES
 from repro.core.sweep import engine as engine_mod
 from repro.core.trace import load_trace, to_workflow
 from repro.core.x64 import enable_x64
-from repro.kernels.sweep_scan import pallas_supported, sweep_scan
+from repro.kernels.sweep_scan import kernel_refusal, sweep_scan
+from repro.kernels.sweep_scan.ops import Refusal
 from repro.kernels.sweep_scan.ref import sweep_scan_ref
 
 from test_trace import FIXTURE_SCAN_EXACT_RTOL
@@ -192,8 +193,9 @@ def test_multiproc_backend_hits_kernel():
 # ---------------- fallback & knob validation --------------------------------------
 
 def test_auto_falls_back_counted(monkeypatch):
-    monkeypatch.setattr(engine_mod.sweep_scan_ops, "pallas_supported",
-                        lambda: False)
+    monkeypatch.setattr(engine_mod.sweep_scan_ops, "kernel_refusal",
+                        lambda: Refusal("no Pallas lowering here",
+                                        fallback=True))
     wfs, cfgs = sweep_pairs("montage_small.json")
     with SweepSession(sim_engine="auto") as sa, \
             SweepSession(sim_engine="xla") as sx:
@@ -204,8 +206,9 @@ def test_auto_falls_back_counted(monkeypatch):
 
 
 def test_forced_pallas_raises_when_unsupported(monkeypatch):
-    monkeypatch.setattr(engine_mod.sweep_scan_ops, "pallas_supported",
-                        lambda: False)
+    monkeypatch.setattr(engine_mod.sweep_scan_ops, "kernel_refusal",
+                        lambda: Refusal("no Pallas lowering here",
+                                        fallback=True))
     wfs, cfgs = sweep_pairs("montage_small.json")
     with SweepSession(sim_engine="pallas") as sess:
         with pytest.raises(RuntimeError, match="[Pp]allas"):
@@ -229,7 +232,7 @@ def test_pallas_supported_on_this_host():
     """CI runs every leg on CPU, where interpret mode must qualify —
     if this fails the whole differential tier above silently tested
     nothing but the fallback."""
-    assert pallas_supported()
+    assert kernel_refusal() is None
     assert jax.default_backend() in ("cpu", "tpu")
 
 
