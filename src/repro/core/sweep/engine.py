@@ -163,14 +163,18 @@ def _make_executable(n_resources: int, exact: bool, mesh=None,
         body = jax_sim._sim_exact if exact else jax_sim._sim_scan
 
         if faulted:
-            def one(a: jax_sim.OpArrays, st_vec: jnp.ndarray,
+            def sim(a: jax_sim.OpArrays, st_vec: jnp.ndarray,
                     f: jax_sim.FaultArrays) -> jnp.ndarray:
                 return body(a, st_vec, n_resources, f)[0]
         else:
-            def one(a: jax_sim.OpArrays, st_vec: jnp.ndarray) -> jnp.ndarray:
+            def sim(a: jax_sim.OpArrays, st_vec: jnp.ndarray) -> jnp.ndarray:
                 return body(a, st_vec, n_resources)[0]
 
-        fn = jax.vmap(one)
+        # the executable's stable name in a device trace: jit_sim_scan
+        # or jit_sim_exact, whatever the bucket or fault state
+        sim.__name__ = sim.__qualname__ = \
+            "sim_exact" if exact else "sim_scan"
+        fn = jax.vmap(sim)
     if mesh is not None:
         return _shard.sharded_executable(fn, mesh,
                                          n_args=3 if faulted else 2)
@@ -343,11 +347,18 @@ class SweepEngine:
             self._rows.move_to_end(key)
             return key, hit[1], hit[2]
         self.stats.row_misses += 1
-        perm = None if exact else jax_sim.scan_order(ops, st)
-        arr = jax_sim.OpArrays.from_micro_ops(ops, pad_to=n_pad, perm=perm)
-        farr = (jax_sim.FaultArrays.from_micro_ops(
-                    ops, n_resources=r_pad, pad_to=n_pad, perm=perm)
-                if jax_sim.faulted(ops) else None)
+        perm = None
+        if not exact:
+            with self.tracer.span("order", phase="host-order",
+                                  ops=ops.n_ops):
+                perm = jax_sim.scan_order(ops, st)
+        # padding, permuting, and each row's copy to the device
+        with self.tracer.span("pack", phase="host-pack", ops=ops.n_ops):
+            arr = jax_sim.OpArrays.from_micro_ops(ops, pad_to=n_pad,
+                                                  perm=perm)
+            farr = (jax_sim.FaultArrays.from_micro_ops(
+                        ops, n_resources=r_pad, pad_to=n_pad, perm=perm)
+                    if jax_sim.faulted(ops) else None)
         self._rows[key] = (ops, arr, farr)
         if len(self._rows) > self.max_row_entries:
             self._rows.popitem(last=False)
@@ -356,8 +367,9 @@ class SweepEngine:
     def _stacked(self, row_keys: Tuple[tuple, ...], ops: List[MicroOps],
                  arrays: List[jax_sim.OpArrays],
                  farrs: Optional[List[Optional[jax_sim.FaultArrays]]],
-                 n_pad: int, r_pad: int):
-        """Stacked bucket batch; an identical re-sweep skips the
+                 vecs: np.ndarray, n_pad: int, r_pad: int):
+        """Stacked bucket batch and the batch's service-time vectors
+        ``vecs`` on the device; an identical re-sweep skips the
         stack + host->device transfer entirely. The entry pins the
         MicroOps references itself: row keys are id()-based, and a row
         entry may be evicted (releasing its pin) while the stack entry
@@ -372,19 +384,24 @@ class SweepEngine:
         if hit is not None:
             self.stats.stack_hits += 1
             self._stacks.move_to_end(row_keys)
-            return hit[1], hit[2]
+            return hit[1], hit[2], jnp.asarray(vecs)
         self.stats.stack_misses += 1
-        batch = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
-        fbatch = None
-        if farrs is not None:
-            neutral = jax_sim.FaultArrays.neutral(n_pad, r_pad)
-            fbatch = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[f if f is not None else neutral for f in farrs])
+        # the host's part only: the stacks and the copy are dispatched
+        # to the device asynchronously, and may finish after the span
+        with self.tracer.span("stack", phase="host-stack",
+                              rows=len(arrays)):
+            batch = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays)
+            fbatch = None
+            if farrs is not None:
+                neutral = jax_sim.FaultArrays.neutral(n_pad, r_pad)
+                fbatch = jax.tree.map(
+                    lambda *xs: jnp.stack(xs),
+                    *[f if f is not None else neutral for f in farrs])
+            st_vecs = jnp.asarray(vecs)
         self._stacks[row_keys] = (tuple(ops), batch, fbatch)
         if len(self._stacks) > self.max_stack_entries:
             self._stacks.popitem(last=False)
-        return batch, fbatch
+        return batch, fbatch, st_vecs
 
     # -- simulation -----------------------------------------------------------
     def simulate_batch(self, ops_list: Sequence[MicroOps],
@@ -429,13 +446,12 @@ class SweepEngine:
                     # the duplicates are sliced off below
                     keyed += [keyed[0]] * (c_pad - len(idxs))
                     vecs += [vecs[0]] * (c_pad - len(idxs))
-                    batch, fbatch = self._stacked(
+                    batch, fbatch, st_vecs = self._stacked(
                         tuple(k for k, _, _ in keyed),
                         [ops_list[i] for i in idxs],
                         [a for _, a, _ in keyed],
                         [f for _, _, f in keyed] if faulted_b else None,
-                        n_pad, r_pad)
-                    st_vecs = jnp.asarray(np.stack(vecs))
+                        np.stack(vecs), n_pad, r_pad)
                 with self.tracer.span(f"sim[{n_pad}x{r_pad}x{c_pad}]",
                                       phase=sim_phase, rows=len(idxs),
                                       shards=shards, faulted=faulted_b):

@@ -272,16 +272,20 @@ def explore(workflow_for: Callable[[Candidate], Workflow],
                             compile_cache=compile_cache,
                             devices=devices, workers=workers)
     key = _objective_key(objective)
-    wfs = [workflow_for(c) for c in candidates]
-    cfgs = [c.to_config() for c in candidates]
-    run = sess.prepare(wfs, cfgs, st=st, locality_aware=locality_aware,
-                       compile_workers=compile_workers)
-    evals = _build_evals(candidates, run.simulate())
-    evals.sort(key=key)
-    _verify(run, evals[:verify_top_k])
-    evals.sort(key=key)
-    _attach_timelines(sess, evals, wfs, cfgs, st,
-                      locality_aware=locality_aware, top_k=timeline_top_k)
+    with sess.tracer.span("explore", phase="search",
+                          candidates=len(candidates),
+                          verify_top_k=verify_top_k):
+        wfs = [workflow_for(c) for c in candidates]
+        cfgs = [c.to_config() for c in candidates]
+        run = sess.prepare(wfs, cfgs, st=st, locality_aware=locality_aware,
+                           compile_workers=compile_workers)
+        evals = _build_evals(candidates, run.simulate())
+        evals.sort(key=key)
+        _verify(run, evals[:verify_top_k])
+        evals.sort(key=key)
+        _attach_timelines(sess, evals, wfs, cfgs, st,
+                          locality_aware=locality_aware,
+                          top_k=timeline_top_k)
     return evals
 
 
@@ -349,16 +353,18 @@ def explore_many(workflows: Sequence, candidates: Sequence[Candidate],
             groups[p.wf_index].append(e)
         return groups
 
-    run = sess.prepare([wf_for(p) for p in pairs],
-                       [p.to_config() for p in pairs], st=st,
-                       locality_aware=locality_aware,
-                       compile_workers=compile_workers)
-    groups = build_groups(run.simulate())
-    for g in groups:
-        g.sort(key=key)
-    _verify(run, [e for g in groups for e in g[:verify_top_k]])
-    for g in groups:
-        g.sort(key=key)
+    with sess.tracer.span("explore_many", phase="search",
+                          candidates=len(pairs), verify_top_k=verify_top_k):
+        run = sess.prepare([wf_for(p) for p in pairs],
+                           [p.to_config() for p in pairs], st=st,
+                           locality_aware=locality_aware,
+                           compile_workers=compile_workers)
+        groups = build_groups(run.simulate())
+        for g in groups:
+            g.sort(key=key)
+        _verify(run, [e for g in groups for e in g[:verify_top_k]])
+        for g in groups:
+            g.sort(key=key)
     return groups
 
 
@@ -400,17 +406,19 @@ def successive_halving(workflow_for: Callable[[Candidate], Workflow],
                             compile_cache=compile_cache,
                             devices=devices, workers=workers)
     key = _objective_key(objective)
-    wfs = [workflow_for(c) for c in candidates]
-    cfgs = [c.to_config() for c in candidates]
-    run = sess.prepare(wfs, cfgs, st=st, locality_aware=locality_aware,
-                       compile_workers=compile_workers)
-    evals = _build_evals(candidates, run.simulate())
-    evals.sort(key=key)
-    while len(evals) > eta:
-        keep = max(len(evals) // eta, 1)
-        evals = evals[:keep]
-        _verify(run, evals)
+    with sess.tracer.span("successive_halving", phase="search",
+                          candidates=len(candidates), eta=eta):
+        wfs = [workflow_for(c) for c in candidates]
+        cfgs = [c.to_config() for c in candidates]
+        run = sess.prepare(wfs, cfgs, st=st, locality_aware=locality_aware,
+                           compile_workers=compile_workers)
+        evals = _build_evals(candidates, run.simulate())
         evals.sort(key=key)
-        if all(e.verified for e in evals):
-            break
+        while len(evals) > eta:
+            keep = max(len(evals) // eta, 1)
+            evals = evals[:keep]
+            _verify(run, evals)
+            evals.sort(key=key)
+            if all(e.verified for e in evals):
+                break
     return evals

@@ -16,6 +16,13 @@ engine/cache operations (counter-asserted by tests/test_obs.py — zero
 extra compiles, zero extra batch calls, bit-identical results), and the
 per-call overhead is one attribute lookup and an empty ``with`` block.
 
+A real `Tracer` also enters every span as a
+``jax.profiler.TraceAnnotation`` named ``<name>|<phase>`` with the meta
+as the event's stats, so under an active profiler session the
+program's spans lie on the device trace's clock; with no session active
+an annotation records nothing. `jax` is imported when a `Tracer` is
+built, never when this module is.
+
 Ownership rule (enforced by tools/check_no_global_state.py): a *real*
 `Tracer` is mutable state and therefore always session-owned — passed
 in via ``SweepSession(tracer=...)`` — never a module-level singleton.
@@ -55,10 +62,19 @@ class Span:
         return (self.name, self.start, self.dur, self.phase, self.meta)
 
 
-class _SpanCtx:
-    """Context manager for one in-flight span; records on exit."""
+def _stat(value: Any) -> Any:
+    """A meta value as a profiler stat: a tuple (of ticket ids, say) as
+    one space-separated string, since the profiler's encoding splits on
+    ``,``."""
+    return " ".join(map(str, value)) if isinstance(value, tuple) else value
 
-    __slots__ = ("_tracer", "_name", "_phase", "_meta", "_t0")
+
+class _SpanCtx:
+    """Context manager for one in-flight span; records on exit. It may
+    be entered and exited out of nesting order (a server's queue span
+    opens at admission and closes at dispatch)."""
+
+    __slots__ = ("_tracer", "_name", "_phase", "_meta", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, phase: str,
                  meta: Tuple[Tuple[str, Any], ...]):
@@ -67,13 +83,19 @@ class _SpanCtx:
         self._phase = phase
         self._meta = meta
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_SpanCtx":
+        self._ann = self._tracer._annotation(
+            f"{self._name}|{self._phase}",
+            **{k: _stat(v) for k, v in self._meta})
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tracer._record(self._name, self._t0, t1 - self._t0,
                              self._phase, self._meta)
 
@@ -107,12 +129,19 @@ class Tracer:
     stable snapshot. ``track`` names the process this tracer belongs to
     — the parent session's tracer is ``"host"``, worker-local tracers
     are re-based into the parent under their worker name by `absorb`.
+
+    Each span is also written into an active `jax.profiler` session as
+    a ``<name>|<phase>`` annotation whose stats are the span's meta
+    (keep string meta free of ``,`` and ``#``, which the profiler's
+    encoding splits on).
     """
 
     enabled = True
 
     def __init__(self, track: str = "host"):
+        from jax.profiler import TraceAnnotation
         self.track = track
+        self._annotation = TraceAnnotation
         self._epoch = time.perf_counter()
         self._spans: List[Span] = []
         self._mu = threading.Lock()

@@ -23,7 +23,7 @@ import asyncio
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 from .request import AdvisorRequest, QueryKey
 
@@ -31,12 +31,22 @@ from .request import AdvisorRequest, QueryKey
 @dataclass
 class Ticket:
     """One admitted request: the future its client awaits, plus the
-    submit instant its deadline is measured from."""
+    submit instant its deadline is measured from. ``req`` is the
+    server's admission id (trace meta, never a cache key); ``queued`` is
+    its open queue-wait span until `dispatched` closes it."""
 
     request: AdvisorRequest
     future: "asyncio.Future"
     submit: float = field(default_factory=time.monotonic)
     timeout_s: Optional[float] = None   # resolved (request or server default)
+    req: int = 0
+    queued: Any = None
+
+    def dispatched(self) -> None:
+        """Close the queue-wait span; later calls do nothing."""
+        if self.queued is not None:
+            self.queued.__exit__(None, None, None)
+            self.queued = None
 
     def waited(self, now: Optional[float] = None) -> float:
         return (time.monotonic() if now is None else now) - self.submit

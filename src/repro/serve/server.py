@@ -22,6 +22,12 @@ pools — and serves every client from it:
                   against any other session user — whose answer fans
                   out to every coalesced sibling
 
+Tracing (docs/observability.md): with a live ``session.tracer`` every
+ticket records ``request|serve`` (submit to answer) and
+``queued|serve-queue`` (admission to the start of its group, expiry, or
+close), both carrying the ticket's admission id as ``req``; every group
+records one ``dispatch|serve``.
+
 Bit-identity contract: every response is element-wise identical to a
 direct per-request `explore()` on a fresh session (tests/test_serve.py
 and the `sweepserve` benchmark counter-assert this, plus coalesced
@@ -38,7 +44,7 @@ import asyncio
 import dataclasses
 import time
 from dataclasses import dataclass
-from typing import List, Optional, Union
+from typing import Dict, List, Optional, Union
 
 from ..core.predictor import Predictor
 from ..core.sweep.search import Evaluation, explore
@@ -119,6 +125,11 @@ class AdvisorServer:
         self.stats = ServeStats()
         self._queue: Optional["asyncio.Queue[Ticket]"] = None
         self._dispatcher: Optional["asyncio.Task"] = None
+        # admitted tickets whose client has not returned yet, by
+        # admission id: `close` fails every one still unanswered,
+        # queued or already taken into a batch
+        self._tickets: Dict[int, Ticket] = {}
+        self._next_req = 0
         self.closed = False
 
     @classmethod
@@ -156,8 +167,10 @@ class AdvisorServer:
         return self
 
     async def close(self) -> None:
-        """Stop dispatching, fail unserved tickets with `ServerClosed`,
-        and close the session if this server owns it. Idempotent."""
+        """Stop dispatching, fail every unanswered ticket (queued, or
+        taken into a batch the dispatcher had not answered) with
+        `ServerClosed`, and close the session if this server owns it.
+        Idempotent."""
         if self.closed:
             return
         self.closed = True
@@ -168,11 +181,10 @@ class AdvisorServer:
             except asyncio.CancelledError:
                 pass
             self._dispatcher = None
-        if self._queue is not None:
-            while not self._queue.empty():
-                t = self._queue.get_nowait()
-                if not t.future.done():
-                    t.future.set_exception(ServerClosed("server closed"))
+        for t in list(self._tickets.values()):
+            t.dispatched()
+            if not t.future.done():
+                t.future.set_exception(ServerClosed("server closed"))
         if self._owns_session:
             self.session.close()
 
@@ -193,11 +205,24 @@ class AdvisorServer:
                                "or await start())")
         timeout = request.timeout_s if request.timeout_s is not None \
             else self.default_timeout_s
-        ticket = Ticket(request, asyncio.get_running_loop().create_future(),
-                        timeout_s=timeout)
-        self.stats.requests += 1
-        await self._queue.put(ticket)
-        return await ticket.future
+        tracer = self.session.tracer
+        req = self._next_req
+        self._next_req += 1
+        with tracer.span("request", phase="serve", req=req):
+            queued = tracer.span("queued", phase="serve-queue", req=req)
+            queued.__enter__()
+            ticket = Ticket(request,
+                            asyncio.get_running_loop().create_future(),
+                            timeout_s=timeout, req=req, queued=queued)
+            self.stats.requests += 1
+            self._tickets[req] = ticket
+            try:
+                await self._queue.put(ticket)
+                return await ticket.future
+            finally:
+                # a client cancelled while queued leaves no span open
+                ticket.dispatched()
+                del self._tickets[req]
 
     # -- dispatcher ------------------------------------------------------------
     async def _serve_loop(self) -> None:
@@ -215,38 +240,46 @@ class AdvisorServer:
         live: List[Ticket] = []
         for t in batch:
             if t.expired():
+                t.dispatched()
                 self.stats.deadline_expired += 1
                 if not t.future.done():
                     t.future.set_exception(
                         DeadlineExceeded(t.waited(), t.timeout_s or 0.0))
             else:
                 live.append(t)
+        tracer = self.session.tracer
         for key, tickets in group_tickets(live).items():
+            for t in tickets:
+                t.dispatched()
             req = tickets[0].request
             digest = self._digest
             evals = self.results.get(key, digest)
             cached = evals is not None
-            if not cached:
-                try:
-                    # one sweep per distinct question, off the event
-                    # loop; the session lock serializes it against any
-                    # other thread driving the same session
-                    self.stats.sweeps += 1
-                    evals = await asyncio.to_thread(self._run_sweep, req)
-                except Exception as exc:          # fail the group cleanly
-                    self.stats.errors += 1
-                    for t in tickets:
-                        if not t.future.done():
-                            t.future.set_exception(exc)
-                    continue
-                self.results.put(key, digest, evals)
-            self.stats.coalesced += len(tickets) - 1
-            for t in tickets:
-                self.stats.responses += 1
-                if not t.future.done():
-                    t.future.set_result(AdvisorResponse(
-                        evaluations=evals, cached=cached,
-                        group_size=len(tickets), latency_s=t.waited()))
+            with tracer.span("dispatch", phase="serve",
+                             reqs=tuple(t.req for t in tickets),
+                             cached=cached, group=len(tickets)):
+                if not cached:
+                    try:
+                        # one sweep per distinct question, off the event
+                        # loop; the session lock serializes it against
+                        # any other thread driving the same session
+                        self.stats.sweeps += 1
+                        evals = await asyncio.to_thread(self._run_sweep,
+                                                        req)
+                    except Exception as exc:      # fail the group cleanly
+                        self.stats.errors += 1
+                        for t in tickets:
+                            if not t.future.done():
+                                t.future.set_exception(exc)
+                        continue
+                    self.results.put(key, digest, evals)
+                self.stats.coalesced += len(tickets) - 1
+                for t in tickets:
+                    self.stats.responses += 1
+                    if not t.future.done():
+                        t.future.set_result(AdvisorResponse(
+                            evaluations=evals, cached=cached,
+                            group_size=len(tickets), latency_s=t.waited()))
 
     def _run_sweep(self, req: AdvisorRequest) -> List[Evaluation]:
         wf = req.workflow

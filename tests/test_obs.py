@@ -425,3 +425,162 @@ def test_multiproc_broken_pool_rollup_with_tracer(monkeypatch):
                        cands, ST, verify_top_k=1, session=ref)
     np.testing.assert_array_equal([e.makespan for e in base],
                                   [e.makespan for e in evals])
+
+
+# ---------------- search, host-prep split, executable names ------------------------
+
+def _within(inner, outer) -> bool:
+    return outer.start <= inner.start and inner.end <= outer.end + 1e-9
+
+
+@pytest.mark.parametrize("entry", ["explore", "explore_many",
+                                   "successive_halving"])
+def test_search_entry_span_wraps_its_sweep(entry):
+    from repro.core.sweep import search
+    tr = Tracer()
+    cands = grid(n_nodes=[6], chunk_sizes=[256 * 1024])
+
+    def wf(c):
+        return W.pipeline(c.n_app, stage_mb=(2, 4, 2, 1))
+    with SweepSession(InlineBackend(), tracer=tr) as sess:
+        if entry == "explore_many":
+            search.explore_many([wf], cands, ST, verify_top_k=2,
+                                session=sess)
+        else:
+            getattr(search, entry)(wf, cands, ST, session=sess)
+    [top] = [s for s in tr.spans() if s.phase == "search"]
+    assert top.name == entry and dict(top.meta)["candidates"] == len(cands)
+    assert all(_within(s, top) for s in tr.spans() if s is not top)
+
+
+def test_host_prep_split_nests_in_prep_and_warm_repeat_records_none():
+    tr = Tracer()
+    with SweepSession(InlineBackend(), tracer=tr) as sess:
+        _sweep(sess)
+        spans = tr.spans()
+        preps = [s for s in spans if s.phase == "host-prep"]
+        parts = [s for s in spans
+                 if s.phase in ("host-order", "host-pack", "host-stack")]
+        assert {s.phase for s in parts} == {"host-order", "host-pack",
+                                            "host-stack"}
+        assert all(any(_within(s, p) for p in preps) for s in parts)
+        # one pack per row miss, one order per scan-mode row miss
+        st = sess.stats
+        assert sum(s.name == "pack" for s in parts) == st.row_misses
+        assert sum(s.name == "stack" for s in parts) == st.stack_misses
+        assert 0 < sum(s.name == "order" for s in parts) < st.row_misses
+        # a warm repeat hits the row and stack caches: no split spans
+        misses = (st.row_misses, st.stack_misses)
+        tr.clear()
+        _sweep(sess)
+        assert (st.row_misses, st.stack_misses) == misses
+        assert any(s.phase == "host-prep" for s in tr.spans())
+        assert not [s for s in tr.spans()
+                    if s.phase in ("host-order", "host-pack", "host-stack")]
+
+
+def _module_name(exact: bool, faulted: bool, kernel: bool = False,
+                 mesh=None) -> str:
+    """The module an engine executable lowers to, at a tiny bucket (four
+    rows, so a four-way mesh splits them)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from repro.core.compile import MAXD
+    from repro.core.sweep.engine import _make_executable
+    from repro.core.x64 import enable_x64
+    c, n, r = 4, 16, 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    with enable_x64():
+        f = jnp.float64
+        args = [jax_sim.OpArrays(
+                    res=s((c, n), jnp.int32), cls=s((c, n), jnp.int32),
+                    nbytes=s((c, n), f), reqs=s((c, n), f),
+                    extra=s((c, n), f), nlat=s((c, n), f),
+                    deps=s((c, n, MAXD), jnp.int32)),
+                s((c, 7), f)]
+        if faulted:
+            args.append(jax_sim.FaultArrays(res_mult=s((c, r), f),
+                                            dead=s((c, n), f)))
+        fn = _make_executable(r, exact, mesh=mesh, faulted=faulted,
+                              kernel=kernel)
+        text = fn.lower(*args).as_text()
+    return re.match(r"module @(\S+) ", text).group(1)
+
+
+@pytest.mark.parametrize("exact,faulted,kernel,name", [
+    (False, False, False, "jit_sim_scan"),
+    (False, True, False, "jit_sim_scan"),
+    (True, False, False, "jit_sim_exact"),
+    (True, True, False, "jit_sim_exact"),
+    (False, False, True, "jit_scan_batch"),
+])
+def test_engine_executables_have_stable_names(exact, faulted, kernel, name):
+    """A device trace names each executable by its mode, not by the
+    bucket it was built for."""
+    assert _module_name(exact, faulted, kernel) == name
+
+
+def test_sharded_executables_keep_their_stable_names():
+    """On a mesh the executable is jit(shard_map(vmap(...))); it keeps
+    its mode's name. Four host devices are forced in a fresh process,
+    since a process's device count is fixed when JAX starts."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    prog = (
+        "import jax, numpy as np\n"
+        "from jax.sharding import Mesh\n"
+        "from repro.core.sweep import SHARD_AXIS\n"
+        "from test_obs import _module_name\n"
+        "mesh = Mesh(np.asarray(jax.devices()[:4]), (SHARD_AXIS,))\n"
+        "assert mesh.devices.size == 4, jax.devices()\n"
+        "for exact in (False, True):\n"
+        "    for faulted in (False, True):\n"
+        "        print(_module_name(exact, faulted, mesh=mesh))\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join([str(root / "src"),
+                                          str(root / "tests")]),
+           "XLA_FLAGS": " ".join(filter(None, (
+               os.environ.get("XLA_FLAGS"),
+               "--xla_force_host_platform_device_count=4")))}
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["jit_sim_scan", "jit_sim_scan",
+                                  "jit_sim_exact", "jit_sim_exact"]
+
+
+# ---------------- spans on the profiler's clock ------------------------------------
+
+def test_annotated_tracer_writes_profiler_events(tmp_path):
+    """A `Tracer` under a profiler session writes each span as a
+    ``<name>|<phase>`` host event with the meta as stats (a tuple as one
+    space-separated string), and two spans closed out of nesting order
+    on one thread (as an event loop closes a queue span) both
+    survive."""
+    import jax
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        a = tr.span("request", phase="serve", req=7)
+        a.__enter__()
+        b = tr.span("queued", phase="serve-queue", req=7, reqs=(7, 8))
+        b.__enter__()
+        time.sleep(0.002)
+        a.__exit__(None, None, None)
+        b.__exit__(None, None, None)
+    [path] = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = {e.name: dict(e.stats)
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events}
+    assert events["request|serve"] == {"req": 7}
+    assert events["queued|serve-queue"] == {"req": 7, "reqs": "7 8"}
+    # the host-clock record is kept as before
+    assert [s.name for s in tr.spans()] == ["request", "queued"]
+    assert dict(tr.spans()[1].meta)["reqs"] == (7, 8)

@@ -11,14 +11,16 @@ measured from submit, the fixed `item_timeout_s` semantics — that fail
 cleanly without wedging the dispatcher.
 """
 import asyncio
+import dataclasses
 
 import numpy as np
 import pytest
 
 from repro.core import (MB, PAPER_RAMDISK, CompileCache, Predictor,
-                        SweepEngine, explore, grid)
+                        SweepEngine, SweepSession, explore, grid)
 from repro.core import workloads as W
 from repro.core.compile import compile_count
+from repro.obs import Tracer
 from repro.serve import (AdvisorRequest, AdvisorServer, DeadlineExceeded,
                          ServerClosed, service_digest)
 
@@ -159,3 +161,109 @@ def test_query_key_is_structural():
     assert a1.query_key() != req(wf_a(), verify_top_k=1).query_key()
     assert a1.query_key() != \
         req(wf_a(), locality_aware=False).query_key()
+
+
+# ---------------- serve spans ------------------------------------------------------
+
+def _named(tr, name, phase):
+    return [s for s in tr.spans() if (s.name, s.phase) == (name, phase)]
+
+
+def _check_ticket_spans(tr, n_tickets):
+    """One ``request|serve`` and one ``queued|serve-queue`` span per
+    admitted ticket, paired by ``req``, the queue wait inside the
+    request."""
+    reqs = {dict(s.meta)["req"]: s for s in _named(tr, "request", "serve")}
+    queued = {dict(s.meta)["req"]: s
+              for s in _named(tr, "queued", "serve-queue")}
+    assert sorted(reqs) == sorted(queued) == list(range(n_tickets))
+    assert len(_named(tr, "request", "serve")) == n_tickets
+    assert len(_named(tr, "queued", "serve-queue")) == n_tickets
+    for i, r in reqs.items():
+        assert r.start <= queued[i].start and queued[i].end <= r.end
+
+
+async def _answered(srv):
+    await srv.submit(req(wf_a(), verify_top_k=1))
+    return 1, [((0,), False)]
+
+
+async def _coalesced(srv):
+    await asyncio.gather(*(srv.submit(req(wf_a(), verify_top_k=1))
+                           for _ in range(3)))
+    return 3, [((0, 1, 2), False)]
+
+
+async def _cached(srv):
+    await srv.submit(req(wf_a(), verify_top_k=1))
+    r = await srv.submit(req(wf_a(), verify_top_k=1))
+    assert r.cached
+    return 2, [((0,), False), ((1,), True)]
+
+
+async def _expired(srv):
+    with pytest.raises(DeadlineExceeded):
+        await srv.submit(req(wf_a(), verify_top_k=1, timeout_s=0.0))
+    return 1, []
+
+
+async def _closed(srv):
+    # both tickets are taken into a batch whose window is still open
+    # when the server closes: each fails, and no span stays open
+    tasks = [asyncio.ensure_future(srv.submit(req(w(), verify_top_k=1)))
+             for w in (wf_a, wf_b)]
+    await asyncio.sleep(0.2)
+    await srv.close()
+    for t in tasks:
+        with pytest.raises(ServerClosed):
+            await asyncio.wait_for(t, timeout=10.0)
+    return 2, []
+
+
+@pytest.mark.parametrize("path", [_answered, _coalesced, _cached, _expired,
+                                  _closed], ids=lambda f: f.__name__[1:])
+def test_every_ticket_yields_request_and_queued_spans(path):
+    tr = Tracer()
+    window = 30.0 if path is _closed else 0.25
+
+    async def main():
+        with SweepSession(tracer=tr) as sess:
+            async with AdvisorServer(ST, session=sess,
+                                     batch_window_s=window) as srv:
+                return await path(srv)
+
+    n_tickets, groups = asyncio.run(main())
+    _check_ticket_spans(tr, n_tickets)
+    dispatch = _named(tr, "dispatch", "serve")
+    got = sorted((dict(s.meta)["reqs"], dict(s.meta)["cached"])
+                 for s in dispatch)
+    assert got == sorted(groups)
+    for s in dispatch:
+        assert dict(s.meta)["group"] == len(dict(s.meta)["reqs"])
+
+
+def test_tracer_off_server_is_bit_identical_with_equal_counters():
+    """The server differential: a live tracer changes no answer and no
+    serving, results-cache, engine or DAG-cache counter."""
+
+    async def drive(tracer):
+        with SweepSession(tracer=tracer) as sess:
+            async with AdvisorServer(ST, session=sess,
+                                     batch_window_s=0.25) as srv:
+                first = await asyncio.gather(*(
+                    srv.submit(req(wf_a() if i % 2 else wf_b(),
+                                   verify_top_k=2)) for i in range(4)))
+                again = await srv.submit(req(wf_a(), verify_top_k=2))
+                with pytest.raises(DeadlineExceeded):
+                    await srv.submit(req(wf_a(), timeout_s=0.0))
+                return ([r.makespans for r in first + [again]],
+                        dataclasses.asdict(srv.stats),
+                        dataclasses.asdict(srv.results.stats),
+                        dataclasses.asdict(sess.stats),
+                        dataclasses.asdict(sess.compile_stats))
+
+    on = asyncio.run(drive(Tracer()))
+    off = asyncio.run(drive(None))
+    for a, b in zip(on[0], off[0]):
+        np.testing.assert_array_equal(a, b)
+    assert on[1:] == off[1:]
