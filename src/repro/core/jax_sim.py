@@ -181,7 +181,113 @@ def faulted(ops: MicroOps) -> bool:
     return ops.res_mult is not None or ops.dead is not None
 
 
-def scan_order(ops: MicroOps, st_ref: ServiceTimes) -> np.ndarray:
+# `scan_order` takes the level-synchronous pass for DAGs of at least this
+# many ops. Below it the per-op loop costs less than building the levels
+# (on CPU the two break even near 4k ops for BLAST's 32-36 levels).
+LEVEL_MIN_OPS = 4096
+# A DAG whose levels average fewer ops than this is left to the loop: the
+# pass pays a few NumPy calls (tens of µs) a level, the loop well under a
+# µs an op, so a deep, narrow DAG such as a chain is cheaper op by op.
+LEVEL_MIN_WIDTH = 64
+
+
+@dataclass(frozen=True)
+class DagLevels:
+    """One DAG's topological level partition, the part of `scan_order`
+    that no service time changes. Ops are relabelled in level order:
+    ``order[j]`` is the op at position j, level k holds positions
+    ``bounds[k]:bounds[k+1]``, and ``deps[:, j]`` are that op's deps as
+    positions, -1 mapped to the sentinel position N whose end is 0.
+    ``order`` None (`SEQUENTIAL`): the per-op loop orders this DAG."""
+
+    order: np.ndarray | None = None     # intp[N]
+    deps: np.ndarray | None = None      # intp[MAXD, N]
+    bounds: tuple = ()
+
+    @property
+    def vectorized(self) -> bool:
+        return self.order is not None
+
+
+SEQUENTIAL = DagLevels()
+
+
+def dag_levels(ops: MicroOps) -> DagLevels:
+    """The DAG's level partition, built frontier by frontier (Kahn) in
+    O(N + E) work and one round of NumPy calls per level; `SEQUENTIAL`
+    for a DAG under `LEVEL_MIN_OPS` ops, for one whose levels would
+    average fewer than `LEVEL_MIN_WIDTH` ops (the build stops there), and
+    for one with a dep at or after its own op, which the compiler never
+    emits: the loop keeps its semantics there (such a dep reads 0)."""
+    n = ops.n_ops
+    deps = ops.deps
+    if n == 0 or n < LEVEL_MIN_OPS or (deps >= np.arange(n)[:, None]).any():
+        return SEQUENTIAL
+    valid = deps >= 0
+    parent = deps[valid]
+    child = np.nonzero(valid)[0]
+    kids = child[np.argsort(parent, kind="stable")]   # CSR children
+    first = np.zeros(n + 1, np.intp)
+    np.cumsum(np.bincount(parent, minlength=n), out=first[1:])
+    indeg = valid.sum(axis=1)
+    max_levels = n // LEVEL_MIN_WIDTH if LEVEL_MIN_WIDTH else n
+    levels = []
+    front = np.flatnonzero(indeg == 0)
+    while front.size:
+        if len(levels) == max_levels:
+            return SEQUENTIAL
+        levels.append(front)
+        lo = first[front]
+        cnt = first[front + 1] - lo
+        pos = np.repeat(lo - np.cumsum(cnt) + cnt, cnt) + np.arange(cnt.sum())
+        ch, hits = np.unique(kids[pos], return_counts=True)
+        indeg[ch] -= hits
+        front = ch[indeg[ch] == 0]
+    order = np.concatenate(levels)
+    assert order.size == n
+    at = np.empty(n + 1, np.intp)
+    at[order] = np.arange(n)
+    at[n] = n
+    dd = np.where(deps >= 0, deps, n)[order]
+    return DagLevels(order=order, deps=np.ascontiguousarray(at[dd].T),
+                     bounds=tuple(np.cumsum([0] + [f.size for f in levels])
+                                  .tolist()))
+
+
+def _starts_by_loop(deps: np.ndarray, dur: np.ndarray) -> np.ndarray:
+    """Estimated starts one op at a time, in emission order."""
+    dur = dur.tolist()
+    ends = [0.0] * len(dur)
+    start = [0.0] * len(dur)
+    for i, row in enumerate(deps.tolist()):
+        s = 0.0
+        for d in row:
+            if d >= 0 and ends[d] > s:
+                s = ends[d]
+        start[i] = s
+        ends[i] = s + dur[i]
+    return np.array(start)
+
+
+def _starts_by_levels(dur: np.ndarray, lv: DagLevels) -> np.ndarray:
+    """Estimated starts one level at a time: each op takes the same max
+    over the same dep ends and the same single add as in the loop (whose
+    max starts at 0: durations are non-negative, so every end is too)."""
+    n = dur.shape[0]
+    durp = dur[lv.order]
+    ends = np.zeros(n + 1)          # position N: the sentinel
+    startp = np.empty(n)
+    for a, b in zip(lv.bounds[:-1], lv.bounds[1:]):
+        s = ends.take(lv.deps[:, a:b]).max(axis=0)
+        startp[a:b] = s
+        np.add(s, durp[a:b], out=ends[a:b])
+    start = np.empty(n)
+    start[lv.order] = startp
+    return start
+
+
+def scan_order(ops: MicroOps, st_ref: ServiceTimes,
+               levels: DagLevels | None = None) -> np.ndarray:
     """Permutation of ops into contention-free estimated-start order.
 
     One forward pass computes each op's earliest start ignoring queueing;
@@ -189,21 +295,19 @@ def scan_order(ops: MicroOps, st_ref: ServiceTimes) -> np.ndarray:
     order at every FIFO resource. Computed against a *reference*
     ServiceTimes — the simulated times stay fully parameterized, only the
     serving order is frozen (tested to stay within a few percent of the
-    exact-order oracle; use exact=True when it must be bit-faithful)."""
+    exact-order oracle; use exact=True when it must be bit-faithful).
+
+    ``levels`` is the DAG's `dag_levels`, built here when not given (the
+    sweep engine caches it per DAG). A vectorized partition runs the pass
+    level by level, `SEQUENTIAL` op by op; both give the same starts to
+    the bit, so the same permutation."""
     from .ref_sim import durations  # shared rate tables
     dur = durations(ops, st_ref) + ops.nlat * st_ref.net_latency
-    n = ops.n_ops
-    est_end = np.zeros(n)
-    est_start = np.zeros(n)
-    deps, ends = ops.deps, est_end
-    for i in range(n):
-        s = 0.0
-        for d in deps[i]:
-            if d >= 0 and ends[d] > s:
-                s = ends[d]
-        est_start[i] = s
-        ends[i] = s + dur[i]
-    return np.argsort(est_start, kind="stable").astype(np.int32)
+    if levels is None:
+        levels = dag_levels(ops)
+    start = (_starts_by_levels(dur, levels) if levels.vectorized
+             else _starts_by_loop(ops.deps, dur))
+    return np.argsort(start, kind="stable").astype(np.int32)
 
 
 def _durations(a: OpArrays, st_vec: jnp.ndarray,

@@ -26,10 +26,14 @@ split eight ways. Batches that don't divide the device count are padded
 into the existing power-of-two buckets (``shard.shard_pad``), never
 recompiled.
 
-Below the executables sit two host-side caches that keep warm sweeps
+Below the executables sit three host-side caches that keep warm sweeps
 device-bound (the Python prep — `scan_order` + padding + host->device
 transfer — otherwise dwarfs the simulation itself):
 
+* a **level cache** of each DAG's topological level partition
+  (`jax_sim.dag_levels`), keyed by DAG identity — a what-if loop, whose
+  new service times miss every row, reorders a known DAG with the
+  vectorized level pass alone;
 * a **row cache** of prepped `OpArrays`, keyed by (DAG identity, service
   times, ops bucket, exact) — subset re-sweeps (halving rounds, what-if
   loops) skip `scan_order` and padding for every row seen before;
@@ -38,7 +42,8 @@ transfer — otherwise dwarfs the simulation itself):
   entirely.
 
 Counters track exact-mode usage (the search layer proves it verifies
-shortlists with one batched call per round), row/batch cache traffic,
+shortlists with one batched call per round), level/row/batch cache
+traffic, the rows `scan_order` ordered by each path,
 and per-device placement (``device_rows``) so sharded runs can show
 where rows actually ran.
 """
@@ -79,6 +84,11 @@ CacheKey = Tuple[int, int, int, bool, int, bool, bool]
 # kernel is scan-only.
 SIM_ENGINES = ("auto", "pallas", "xla")
 
+# DAGs whose level partition (`jax_sim.dag_levels`) the engine keeps: the
+# DAG cache's default capacity, so every DAG a session's sweeps reuse
+# keeps its levels
+MAX_LEVEL_ENTRIES = 256
+
 # a sharded bucket must carry at least this many real op-rows
 # (candidates x padded op count); below it the per-device dispatch
 # overhead exceeds the parallelism win (measured on 8 forced host
@@ -100,6 +110,11 @@ class CacheStats:
     padded_rows: int = 0          # rows actually simulated incl. padding
     row_hits: int = 0             # prepped-OpArrays cache traffic
     row_misses: int = 0
+    level_hits: int = 0           # per-DAG level-partition cache traffic
+    level_misses: int = 0         # (`jax_sim.dag_levels`, for scan_order)
+    level_rows: int = 0           # rows scan_order ordered level by level
+    loop_rows: int = 0            # ... op by op (small, deep or
+                                  # non-topological DAGs)
     stack_hits: int = 0           # stacked-bucket-batch cache traffic
     stack_misses: int = 0
     sharded_batch_calls: int = 0  # simulate_batch calls that sharded >= 1 bucket
@@ -242,6 +257,9 @@ class SweepEngine:
         self._rows: "OrderedDict[tuple, tuple]" = OrderedDict()
         # tuple of row keys (+ batch shape) -> stacked device batch
         self._stacks: "OrderedDict[tuple, object]" = OrderedDict()
+        # id(ops) -> (ops ref, jax_sim.DagLevels): scan_order's structure,
+        # which no service time changes; the reference pins the id()
+        self._levels: "OrderedDict[int, tuple]" = OrderedDict()
         self._mesh = _shard.resolve_mesh(devices)
         self.stats = CacheStats()
 
@@ -327,8 +345,24 @@ class SweepEngine:
         self._fns.clear()
         self._rows.clear()
         self._stacks.clear()
+        self._levels.clear()
 
     # -- host-prep caches ------------------------------------------------------
+    def _dag_levels(self, ops: MicroOps) -> jax_sim.DagLevels:
+        """The DAG's level partition, built on its first scan-mode row
+        and reused for every service-time vector after it."""
+        hit = self._levels.get(id(ops))
+        if hit is not None:
+            self.stats.level_hits += 1
+            self._levels.move_to_end(id(ops))
+            return hit[1]
+        self.stats.level_misses += 1
+        levels = jax_sim.dag_levels(ops)
+        self._levels[id(ops)] = (ops, levels)
+        if len(self._levels) > MAX_LEVEL_ENTRIES:
+            self._levels.popitem(last=False)
+        return levels
+
     def _prepped_row(self, ops: MicroOps, st: ServiceTimes, n_pad: int,
                      r_pad: int, exact: bool
                      ) -> Tuple[tuple, jax_sim.OpArrays,
@@ -351,7 +385,12 @@ class SweepEngine:
         if not exact:
             with self.tracer.span("order", phase="host-order",
                                   ops=ops.n_ops):
-                perm = jax_sim.scan_order(ops, st)
+                levels = self._dag_levels(ops)
+                perm = jax_sim.scan_order(ops, st, levels)
+            if levels.vectorized:
+                self.stats.level_rows += 1
+            else:
+                self.stats.loop_rows += 1
         # padding, permuting, and each row's copy to the device
         with self.tracer.span("pack", phase="host-pack", ops=ops.n_ops):
             arr = jax_sim.OpArrays.from_micro_ops(ops, pad_to=n_pad,

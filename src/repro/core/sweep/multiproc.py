@@ -62,7 +62,8 @@ from .engine import SweepEngine
 # engine / compile-cache counters that roll up from workers by summation
 _ENGINE_ROLLUP = ("hits", "misses", "evictions", "batch_calls",
                   "exact_batch_calls", "sims", "exact_sims", "padded_rows",
-                  "row_hits", "row_misses", "stack_hits", "stack_misses",
+                  "row_hits", "row_misses", "level_hits", "level_misses",
+                  "level_rows", "loop_rows", "stack_hits", "stack_misses",
                   "kernel_buckets", "kernel_fallbacks")
 _CACHE_ROLLUP = ("hits", "misses", "evictions", "disk_hits", "disk_stores")
 
