@@ -1,5 +1,5 @@
 """What every driver shares: the outcome of one request, the program's
-session, and the plain-to-program conversions.
+session on the cell's chips, and the plain-to-program conversions.
 
 A driver is ``bench/drivers/<name>.py`` with a class ``Driver(cell,
 gen, *, tracer, dag_dir, hooks)`` whose ``run(seconds)`` warms up every
@@ -63,9 +63,16 @@ class Base:
         self.window = (0.0, 0.0)
 
     def session(self):
-        from repro.core import SweepSession
+        """The program's session on the cell's chips: one chip runs
+        inline, more split each bucket's candidates over the first
+        ``chips`` devices (`ShardedBackend`, the engine's own threshold
+        deciding which buckets shard)."""
+        from repro.core import InlineBackend, ShardedBackend, SweepSession
+        chips = self.cell.chips
+        backend = ShardedBackend(chips) if chips > 1 else InlineBackend()
         disk = self.cell.traffic.get("dag_disk_cache")
-        return SweepSession(cache_dir=str(self.dag_dir) if disk else None,
+        return SweepSession(backend,
+                            cache_dir=str(self.dag_dir) if disk else None,
                             tracer=self.tracer)
 
     def question(self, req):

@@ -2,7 +2,8 @@
 a seeded stream of requests out, each with the time it is due.
 
 A request is one operator question: a workflow per candidate (the
-candidate's app nodes set the workflow's width), the candidate grid, the
+candidate's app nodes set the workflow's width, its family's file
+``bench/families/<family>.py`` builds it), the candidate grid, the
 service times to judge it under, and ``verify_top_k``. A mix
 (``bench/traffic/<mix>.json``) is these parameters:
 
@@ -43,9 +44,9 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from . import spec as S
 from . import workflows as W
 
-MB = W.MB
 KB = W.KB
 
 
@@ -92,29 +93,9 @@ def layouts_for(cfg: dict, split) -> list:
 
 def build_workflow(cfg: dict, spec: dict, n_app: int, cut: int = 0) -> dict:
     """One workflow of the configuration, ``n_app`` wide, every file
-    ``cut`` bytes smaller than the configuration states."""
-    fam = spec["family"]
-    if fam == "blast":
-        return W.blast(n_app, n_queries=cfg["n_queries"],
-                       db_bytes=cfg["db_mb"] * MB - cut,
-                       per_query_s=cfg["per_query_s"],
-                       query_bytes=cfg["query_mb"] * MB - cut,
-                       out_bytes=cfg["out_mb"] * MB - cut)
-    if fam == "reduce":
-        return W.reduce_(n_app, in_bytes=cfg["reduce_in_mb"] * MB - cut,
-                         mid_bytes=cfg["reduce_mid_mb"] * MB - cut,
-                         out_bytes=cfg["reduce_out_mb"] * MB - cut,
-                         wass=spec["wass"])
-    if fam == "broadcast":
-        return W.broadcast(n_app,
-                           file_bytes=cfg["broadcast_file_mb"] * MB - cut,
-                           out_bytes=cfg["broadcast_out_mb"] * MB - cut,
-                           replication=spec["replication"])
-    if fam == "stripe":
-        return W.stripe(n_app, file_bytes=cfg["stripe_file_mb"] * MB - cut,
-                        n_hot=cfg["stripe_hot_files"],
-                        out_bytes=cfg["stripe_out_mb"] * MB - cut)
-    raise ValueError(f"unknown workflow family {fam!r}")
+    ``cut`` bytes smaller than the configuration states: the family the
+    spec names, ``bench/families/<family>.py``."""
+    return S.load_family(spec["family"])(cfg, spec, n_app, cut)
 
 
 def _classes(cfg: dict, mix: dict) -> list:

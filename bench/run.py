@@ -154,7 +154,7 @@ def per_layer(cell, drv, window, refs, device_kind):
     td = reduce_trace(window.xplane())
     ctx = {"trace": td, "outcomes": drv.outcomes, "refs": refs,
            "peaks": json.loads((S.BENCH / "peaks.json").read_text()),
-           "device_kind": device_kind}
+           "device_kind": device_kind, "chips": cell.chips}
     metrics = {}
     for m in cell.per_layer:
         v = S.load_reader(m["name"])(ctx)

@@ -7,14 +7,20 @@ metric lives in a file of its own, found by name:
     bench/traffic/<traffic>.json  the mix's parameters, for `generator`
     bench/drivers/<driver>.py     the entry a mix is served through
                                   (its ``driver``), a class `Driver`
+    bench/families/<family>.py    a workflow family a configuration's
+                                  ``workflows`` name (``family``), a
+                                  function ``build(cfg, spec, n_app,
+                                  cut)`` that returns the plain workflow
+                                  (`bench.workflows`' form)
     bench/e2e/<metric>.py         an end-to-end metric's `read(ctx)`
     bench/layers/<metric>.py      a per-layer metric's `read(ctx)`
 
-so a cell, a mix, a driver or a metric is added with files and entries
-alone.
+so a cell, a mix, a driver, a workflow family or a metric is added with
+files and entries alone.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -41,6 +47,10 @@ def e2e_path(metric: str) -> Path:
 
 def driver_path(name: str) -> Path:
     return BENCH / "drivers" / f"{name}.py"
+
+
+def family_path(name: str) -> Path:
+    return BENCH / "families" / f"{name}.py"
 
 
 class Cell:
@@ -90,3 +100,10 @@ def load_e2e(metric: str):
 def load_driver(name: str):
     """The `Driver` class that serves a mix."""
     return _load(driver_path(name)).Driver
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str):
+    """The `build(cfg, spec, n_app, cut)` function of a workflow family,
+    loaded once: the generator builds workflows inside the window."""
+    return _load(family_path(name)).build
