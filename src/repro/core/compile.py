@@ -20,6 +20,7 @@ Each micro-op i:
               delays dependents but does NOT occupy the queue)
     deps[i,:] up to MAXD predecessor op ids (-1 = none); fan-in larger
               than MAXD is reduced through zero-cost barrier trees
+              (`MicroOps.n_barriers` counts their ops)
 
 Resource map (R = 1 + 4H + S + 1):
     0                      dummy (barriers)
@@ -81,6 +82,7 @@ class MicroOps:
     file_write_op: Dict[str, int] = field(default_factory=dict)
     bytes_moved: int = 0
     storage_used: int = 0
+    n_barriers: int = 0    # zero-cost barrier ops (res 0, CLS_NONE)
     # fault injection (docs/faults.md) — None for healthy compiles, so a
     # healthy MicroOps is byte-for-byte what the pre-fault compiler built
     res_mult: Optional[np.ndarray] = None   # float64[n_resources] service-time
@@ -116,6 +118,7 @@ class _Builder:
         self.deps: List[List[int]] = []
         self.dead_flags: List[float] = []
         self.bytes_moved = 0
+        self.n_barriers = 0
         self.storage_idx = {h: i for i, h in enumerate(config.storage_hosts)}
         # the manager supplies read-side replica choice (failover +
         # degradation steering); degraded maps host -> service multiplier
@@ -149,6 +152,8 @@ class _Builder:
         self.nlat.append(float(nlat))
         self.deps.append(list(deps) + [-1] * (MAXD - len(deps)))
         self.dead_flags.append(1.0 if dead else 0.0)
+        if cls == CLS_NONE and not dead:
+            self.n_barriers += 1
         return i
 
     def dead_op(self, deps: Sequence[int]) -> int:
@@ -372,6 +377,7 @@ def compile_workflow(wf: Workflow, cfg: StorageConfig, *,
         file_write_op={k: v for k, v in file_write_op.items() if v >= 0},
         bytes_moved=b.bytes_moved,
         storage_used=mgr.storage_used(),
+        n_barriers=b.n_barriers,
         res_mult=res_mult,
         dead=dead_arr,
     )

@@ -57,7 +57,8 @@ CompileKey = Tuple[str, str, bool]
 # as `SysIdReport.save/load`): any change to the emitted-DAG semantics
 # invalidates every persisted entry rather than silently serving DAGs a
 # newer compiler would not produce.
-_FORMAT_VERSION = 2   # v2: optional fault arrays (res_mult / dead)
+_FORMAT_VERSION = 3   # v2: optional fault arrays (res_mult / dead);
+                      # v3: the barrier-op count
 
 
 def compiler_digest() -> str:
@@ -94,6 +95,7 @@ def _dump_ops(path: Path, key: CompileKey, ops: MicroOps) -> None:
         "n_resources": ops.n_resources,
         "bytes_moved": ops.bytes_moved,
         "storage_used": ops.storage_used,
+        "n_barriers": ops.n_barriers,
         "task_end_op": {str(k): v for k, v in ops.task_end_op.items()},
         "stage_of_task": {str(k): v for k, v in ops.stage_of_task.items()},
         "file_write_op": dict(ops.file_write_op),
@@ -135,6 +137,7 @@ def _load_ops(path: Path, key: CompileKey) -> Optional[MicroOps]:
                        for k, v in meta["file_write_op"].items()},
         bytes_moved=int(meta["bytes_moved"]),
         storage_used=int(meta["storage_used"]),
+        n_barriers=int(meta["n_barriers"]),
     )
 
 
@@ -153,6 +156,8 @@ class CompileCacheStats:
     dedup_shared: int = 0      # candidates served by a classmate's DAG
     disk_hits: int = 0         # lookups served from the persistence dir
     disk_stores: int = 0       # entries written to the persistence dir
+    barrier_ops: int = 0       # zero-cost barrier ops of the DAGs the
+                               # cache compiled (`MicroOps.n_barriers`)
     worker_compiles: Dict[str, int] = dataclasses.field(default_factory=dict)
                                # compile_workflow executions per multiproc
                                # worker process (rolled up by MultiprocSweep;
@@ -217,12 +222,19 @@ class CompileCache:
         if not self.enabled:
             with self._mu:
                 self.stats.misses += 1
-            return compile_workflow(wf, cfg, locality_aware=locality_aware)
+            return self._compile(wf, cfg, locality_aware)
         key = compile_key(wf, cfg, locality_aware=locality_aware)
         ops = self._lookup(key)
         if ops is None:
-            ops = compile_workflow(wf, cfg, locality_aware=locality_aware)
+            ops = self._compile(wf, cfg, locality_aware)
             self._insert(key, ops)
+        return ops
+
+    def _compile(self, wf: Workflow, cfg: StorageConfig,
+                 locality_aware: bool) -> MicroOps:
+        ops = compile_workflow(wf, cfg, locality_aware=locality_aware)
+        with self._mu:
+            self.stats.barrier_ops += ops.n_barriers
         return ops
 
     # -- grid compile ----------------------------------------------------------
@@ -245,8 +257,7 @@ class CompileCache:
         cfgs = [c.to_config() for c in candidates]
 
         def build(i: int) -> MicroOps:
-            return compile_workflow(wfs[i], cfgs[i],
-                                    locality_aware=locality_aware)
+            return self._compile(wfs[i], cfgs[i], locality_aware)
 
         def build_many(idxs: Sequence[int]) -> List[MicroOps]:
             if workers is not None and workers > 1 and len(idxs) > 1:

@@ -386,11 +386,17 @@ class SweepEngine:
             with self.tracer.span("order", phase="host-order",
                                   ops=ops.n_ops):
                 levels = self._dag_levels(ops)
-                perm = jax_sim.scan_order(ops, st, levels)
-            if levels.vectorized:
-                self.stats.level_rows += 1
-            else:
-                self.stats.loop_rows += 1
+                if levels.vectorized:
+                    self.stats.level_rows += 1
+                    perm = jax_sim.scan_order(ops, st, levels)
+                else:
+                    # too small, deep or narrow for the level pass: the
+                    # per-op loop orders this row
+                    self.stats.loop_rows += 1
+                    with self.tracer.span("order_loop",
+                                          phase="host-order-loop",
+                                          ops=ops.n_ops):
+                        perm = jax_sim.scan_order(ops, st, levels)
         # padding, permuting, and each row's copy to the device
         with self.tracer.span("pack", phase="host-pack", ops=ops.n_ops):
             arr = jax_sim.OpArrays.from_micro_ops(ops, pad_to=n_pad,

@@ -12,6 +12,9 @@ that space:
     iterative   depth rounds of map -> shuffle -> reduce
     straggler   a pipeline where one chain per level draws a heavy
                 compute + output-size multiplier
+    montage     the 2MASS mosaic's nine transformations (Juve et al.,
+                FGCS 29(3), 2013) on a width x width image grid, cut
+                into tiles x tiles tiles
 
 File sizes are lognormal (``mean_mb`` / ``sigma`` — crank ``sigma`` for
 heavy-tailed, skewed mixes), fan-out degrees Zipf(``zipf_a``), and every
@@ -37,10 +40,25 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..types import MB
+from ..types import MB, FileAttr, Placement
 from .ir import TraceError, TraceTask, TraceWorkflow
 
-FAMILIES = ("pipeline", "fan_out", "fan_in", "iterative", "straggler")
+FAMILIES = ("pipeline", "fan_out", "fan_in", "iterative", "straggler",
+            "montage")
+
+# Montage's transformations (Juve et al., FGCS 29(3), 2013): the MB each
+# writes and its mean runtime in seconds. A tile's mosaic is
+# MONTAGE_MB["mAdd"] per image it co-adds, a shrunk tile MONTAGE_SHRINK
+# times smaller; the raw images are MONTAGE_RAW_MB each.
+MONTAGE_RAW_MB = 2.0
+MONTAGE_SHRINK = 16
+MONTAGE_MB = {"mProjectPP": 8.0, "mDiffFit": 1 / 16, "mConcatFit": 1.0,
+              "mBgModel": 0.1, "mBackground": 8.0, "mImgtbl": 0.25,
+              "mAdd": 4.0, "mJPEG": 1.0}
+MONTAGE_RUNTIME_S = {"mProjectPP": 1.73, "mDiffFit": 0.66,
+                     "mConcatFit": 143.26, "mBgModel": 384.49,
+                     "mBackground": 1.72, "mImgtbl": 2.78, "mAdd": 282.37,
+                     "mShrink": 66.10, "mJPEG": 0.64}
 
 
 @dataclass(frozen=True)
@@ -59,6 +77,9 @@ class GenSpec:
     runtime_s: float = 0.0    # per-task compute seconds
     straggler_factor: float = 8.0   # straggler compute+size multiplier
     size_quantum: int = MB    # sizes round up to a multiple of this
+    tiles: int = 2            # montage: tiles to a side of the mosaic
+    wass: bool = False        # montage: collocate the fits, and each
+                              # tile's corrected images
 
     def replace(self, **kw) -> "GenSpec":
         return replace(self, **kw)
@@ -76,6 +97,9 @@ def _check(spec: GenSpec) -> None:
                          f"{spec.mean_mb}/{spec.sigma}")
     if spec.max_degree < 1 or spec.size_quantum < 1:
         raise TraceError("max_degree and size_quantum must be >= 1")
+    if spec.family == "montage" and not 1 <= spec.tiles <= spec.width:
+        raise TraceError(f"montage tiles must be in [1, width], got "
+                         f"{spec.tiles}")
 
 
 def _size(rng: np.random.Generator, spec: GenSpec, scale: float = 1.0) -> int:
@@ -99,9 +123,20 @@ class _Ctx:
         self.rng = np.random.default_rng(seed)
         self.tasks: List[TraceTask] = []
         self.sizes: Dict[str, int] = {}
+        self.hints: Dict[str, FileAttr] = {}
 
     def file(self, name: str, scale: float = 1.0) -> str:
         self.sizes[name] = _size(self.rng, self.spec, scale)
+        return name
+
+    def sized(self, name: str, mb: float) -> str:
+        """A file of ``mb`` MB, times a lognormal factor of median 1
+        where ``sigma`` > 0."""
+        size = int(mb * MB)
+        if self.spec.sigma > 0:
+            size = max(int(size * math.exp(
+                self.rng.normal(0.0, self.spec.sigma))), 1)
+        self.sizes[name] = size
         return name
 
     def task(self, tid: str, category: str, inputs: Tuple[str, ...],
@@ -193,6 +228,66 @@ def _gen_iterative(ctx: _Ctx) -> None:
             inputs.append(out)
 
 
+def _gen_montage(ctx: _Ctx) -> None:
+    """Images on a k x k grid (k = width): each projected, each pair of
+    right, down and down-right neighbours fitted, the fits concatenated
+    into one background model that corrects every image; then per tile
+    a table, a co-add and a shrink, and once more over the shrunk tiles,
+    rendered as a JPEG. Every task goes to the compiler's scheduler."""
+    spec = ctx.spec
+    k, t = spec.width, spec.tiles
+    mb, run = MONTAGE_MB, MONTAGE_RUNTIME_S
+    grid = [(r, c) for r in range(k) for c in range(k)]
+    img = {rc: f"{rc[0]}_{rc[1]}" for rc in grid}
+    tiles: Dict[Tuple[int, int], List[Tuple[int, int]]] = {}
+    for r, c in grid:
+        tiles.setdefault((r * t // k, c * t // k), []).append((r, c))
+
+    def task(kind: str, inputs, outputs, group: Optional[str] = None):
+        if spec.wass and group is not None:
+            for f in outputs:
+                ctx.hints[f] = FileAttr(placement=Placement.COLLOCATE,
+                                        collocate_group=group)
+        ctx.task(f"{kind}_{len(ctx.tasks)}", kind, tuple(inputs),
+                 tuple(outputs), runtime=run[kind])
+
+    for rc in grid:
+        task("mProjectPP", [ctx.sized(f"raw{img[rc]}", MONTAGE_RAW_MB)],
+             [ctx.sized(f"proj{img[rc]}", mb["mProjectPP"])])
+    fits = []
+    for r, c in grid:
+        for dr, dc in ((0, 1), (1, 0), (1, 1)):
+            if r + dr < k and c + dc < k:
+                a, b = img[(r, c)], img[(r + dr, c + dc)]
+                fits.append(ctx.sized(f"fit{a}-{b}", mb["mDiffFit"]))
+                task("mDiffFit", [f"proj{a}", f"proj{b}"], [fits[-1]],
+                     "fits")
+    task("mConcatFit", fits, [ctx.sized("fits.tbl", mb["mConcatFit"])])
+    task("mBgModel", ["fits.tbl"],
+         [ctx.sized("corrections", mb["mBgModel"])])
+    name = {tile: f"{tile[0]}_{tile[1]}" for tile in tiles}
+    for r, c in grid:
+        task("mBackground", [f"proj{img[(r, c)]}", "corrections"],
+             [ctx.sized(f"corr{img[(r, c)]}", mb["mBackground"])],
+             f"tile{name[(r * t // k, c * t // k)]}")
+    for tile, rcs in tiles.items():
+        task("mImgtbl", [f"corr{img[rc]}" for rc in rcs],
+             [ctx.sized(f"tile{name[tile]}.tbl", mb["mImgtbl"])])
+    for tile, rcs in tiles.items():
+        task("mAdd", [f"tile{name[tile]}.tbl"]
+             + [f"corr{img[rc]}" for rc in rcs],
+             [ctx.sized(f"mosaic{name[tile]}", mb["mAdd"] * len(rcs))])
+    shrunk = []
+    for tile, rcs in tiles.items():
+        shrunk.append(ctx.sized(f"shrunk{name[tile]}",
+                                mb["mAdd"] * len(rcs) / MONTAGE_SHRINK))
+        task("mShrink", [f"mosaic{name[tile]}"], [shrunk[-1]])
+    task("mImgtbl", shrunk, [ctx.sized("mosaic.tbl", mb["mImgtbl"])])
+    task("mAdd", ["mosaic.tbl"] + shrunk,
+         [ctx.sized("mosaic", mb["mAdd"] * k * k / MONTAGE_SHRINK)])
+    task("mJPEG", ["mosaic"], [ctx.sized("mosaic.jpg", mb["mJPEG"])])
+
+
 def generate(spec: GenSpec, seed: int = 0) -> TraceWorkflow:
     """One workflow of the family — deterministic in ``(spec, seed)``."""
     _check(spec)
@@ -203,10 +298,12 @@ def generate(spec: GenSpec, seed: int = 0) -> TraceWorkflow:
         _gen_fan_out(ctx)
     elif spec.family == "fan_in":
         _gen_fan_in(ctx)
+    elif spec.family == "montage":
+        _gen_montage(ctx)
     else:
         _gen_iterative(ctx)
     tw = TraceWorkflow(name=f"{spec.family}_s{seed}", tasks=ctx.tasks,
-                       file_sizes=ctx.sizes)
+                       file_sizes=ctx.sizes, hints=ctx.hints)
     tw.validate()
     return tw
 
